@@ -47,6 +47,8 @@ def main() -> None:
                         "against; ratios go to stdout + BENCH_compare.json")
     args = p.parse_args()
 
+    from repro.launch import compile_cache
+    compile_cache.enable()
     from benchmarks import (fig1c_eviction, fig4_throughput, fig56_latency,
                             fig7_psf, fig9_overhead, fig10_car,
                             fig11_hotness, fig_faults, fig_prefetch,

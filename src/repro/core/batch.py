@@ -590,7 +590,9 @@ def _exec_runtime(cfg: PlaneConfig, s: st.PlaneState, obj_plan: jnp.ndarray,
             obj_of=obj_of.reshape(V, P),
             alloc_count=s.alloc_count.at[v_new_m].add(1),
             live_count=live,
-            cat=s.cat.reshape(V * P).at[dst_flat].set(True).reshape(V, P),
+            # bool tables scatter in 2-D: a flat [V*P] bool scatter takes the
+            # TPU compiler minutes at 1 Mi records
+            cat=s.cat.at[v_new_m, slot_new].set(True),
         )
         # GC source pages this batch fully drained (deferred equivalent of
         # the scalar path's per-move _kill_old_copy)

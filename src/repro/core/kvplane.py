@@ -754,13 +754,12 @@ def _jitted_sharded_decode(cfg: KVPlaneConfig, mode: str, mesh):
         return jax.jit(functools.partial(sharded_sparse_decode, cfg,
                                          mode=mode),
                        donate_argnums=(0,))
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     sp = jax.tree.map(lambda _: P("far"),
                       jax.eval_shape(functools.partial(init, cfg)))
-    fn = shard_map(functools.partial(_sharded_decode_body, cfg, mode),
-                   mesh=mesh, in_specs=(sp, P(), P()),
-                   out_specs=(P(), sp), check_rep=False)
+    fn = jax.shard_map(functools.partial(_sharded_decode_body, cfg, mode),
+                       mesh=mesh, in_specs=(sp, P(), P()),
+                       out_specs=(P(), sp), check_vma=False)
     return jax.jit(fn, donate_argnums=(0,))
 
 

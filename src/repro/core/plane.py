@@ -14,7 +14,7 @@ Eviction happens only page-granularly inside ``paths.alloc_frame`` (egress
 path, paper §4.1) — the PSF of the victim is recomputed from its CAR
 there.  ``evacuate`` is the concurrent compactor analogue: victims are
 selected by garbage ratio and their live rows are re-packed hot/cold
-through the ``kernels.compact`` page-assembly kernel.
+through ``ops.compact_pages``, one batched row gather.
 """
 from __future__ import annotations
 
@@ -264,7 +264,7 @@ def execute_evacuate(cfg: PlaneConfig, s: st.PlaneState, plan: EvacPlan,
                      garbage_threshold: float | None = None, *,
                      clear_access: bool = True, shard=None) -> st.PlaneState:
     """Compact the planned victim pages (hot/cold segregation by access
-    bit, ``kernels.compact`` page assembly).  Each victim's eligibility is
+    bit, ``ops.compact_pages`` page assembly).  Each victim's eligibility is
     re-checked against the *current* state — a stale plan entry (page
     evicted, drained, or pinned since planning) is skipped, so a plan may
     safely execute several dispatch gaps after it was made.
@@ -333,8 +333,8 @@ def execute_evacuate(cfg: PlaneConfig, s: st.PlaneState, plan: EvacPlan,
             v_dst = jnp.where(hotm, hv, cv)
             s_dst = jnp.where(hotm, hslot, cslot)
 
-            # assemble the (up to four) destination pages with the compact
-            # kernel: each destination slot DMAs its source row directly
+            # assemble the (up to four) destination pages in one row
+            # gather: each destination slot DMAs its source row directly
             src_flat = f_src * P + jnp.arange(P, dtype=jnp.int32)
             dest_pages = jnp.stack([hc, hf, cc, cf])          # [4]
             dpi = jnp.where(hotm, jnp.where(hcur, 0, 1),
@@ -352,17 +352,16 @@ def execute_evacuate(cfg: PlaneConfig, s: st.PlaneState, plan: EvacPlan,
 
             # smart pointers + occupancy + preserved profiling bits
             # (the evacuator preserves card bits across the move, §4.3)
-            dst_flat = jnp.where(occ, v_dst * P + s_dst, V * P)
+            # 2-D scatters (row V = dropped): a flat [V*P] bool scatter
+            # takes the TPU compiler minutes at 1 Mi records
+            dst_v = jnp.where(occ, v_dst, V)
             s = s._replace(
                 frames=frames,
                 obj_loc=s.obj_loc.at[jnp.where(occ, objs, O)].set(
                     v_dst * P + s_dst),
-                obj_of=s.obj_of.reshape(V * P).at[dst_flat].set(
-                    objs).reshape(V, P),
-                cat=s.cat.reshape(V * P).at[dst_flat].set(
-                    was_carded).reshape(V, P),
-                access=s.access.reshape(V * P).at[dst_flat].set(
-                    hotm).reshape(V, P),
+                obj_of=s.obj_of.at[dst_v, s_dst].set(objs),
+                cat=s.cat.at[dst_v, s_dst].set(was_carded),
+                access=s.access.at[dst_v, s_dst].set(hotm),
                 stats=st.bump(s.stats, evac_moved=n_moved),
             )
             # the moved rows are in place — NOW the retired cursors may be
@@ -399,8 +398,8 @@ def evacuate(cfg: PlaneConfig, s: st.PlaneState,
     the rest to a cold one — manufacturing the spatial locality that lets
     subsequent accesses take the cheap paging path.  Each victim's moves
     are planned as two append streams and executed with the
-    ``kernels.compact`` page-assembly kernel (one gather-DMA per
-    destination page) instead of a per-slot append chain.  All access bits
+    ``ops.compact_pages`` row gather (one gather-DMA per
+    destination row) instead of a per-slot append chain.  All access bits
     are cleared at the end (paper: "cleared by the evacuator at the end of
     each evacuation").
 

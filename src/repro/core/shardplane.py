@@ -72,8 +72,7 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental.shard_map import shard_map
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ..kernels import ops as kops
 from . import baselines
@@ -148,6 +147,19 @@ def make_config(cfg: PlaneConfig, shards: int, shard_batch: int,
 def create(cfg: ShardedPlaneConfig, initial: jnp.ndarray) -> st.PlaneState:
     """Stacked ``[S, ...]`` plane over the global ``[S*O, D]`` objects."""
     return st.create_sharded(cfg.shard, cfg.shards, initial)
+
+
+@functools.lru_cache(maxsize=None)
+def jitted_create(cfg: ShardedPlaneConfig, mesh=None):
+    """``create`` as one compiled program.  On a ``far`` mesh the objects
+    arrive split over ``far`` and every output leaf is laid out
+    ``P("far")``, so each device builds only its own shard's state."""
+    if mesh is None:
+        return jax.jit(partial(create, cfg))
+    far = NamedSharding(mesh, P("far"))
+    return jax.jit(partial(create, cfg), in_shardings=far,
+                   out_shardings=jax.tree.map(lambda _: far,
+                                              _state_specs(cfg)))
 
 
 # --------------------------------------------------------------------------
@@ -611,9 +623,9 @@ def jitted_phase_probe(cfg: ShardedPlaneConfig, phase: str, mesh):
     then ``"ingress"`` (pack + fused collective), then a full access gives
     the subtractive pack / collective / serve wall-share breakdown."""
     assert phase in ("pack", "ingress"), phase
-    fn = shard_map(partial(_probe_body, cfg, phase), mesh=mesh,
-                   in_specs=(P("far"),), out_specs=P("far"),
-                   check_rep=False)
+    fn = jax.shard_map(partial(_probe_body, cfg, phase), mesh=mesh,
+                       in_specs=(P("far"),), out_specs=P("far"),
+                       check_vma=False)
     return jax.jit(fn)
 
 
@@ -635,13 +647,13 @@ def _jitted_access(cfg: ShardedPlaneConfig, mode, mesh, with_served,
         return jax.jit(partial(access, cfg, mode=mode, degraded=degraded,
                                with_served=with_served))
     sp = _state_specs(cfg)
-    # check_rep=False: the plane engine contains fori/while loops, which
-    # shard_map's replication checker cannot rule on (the state is
+    # check_vma=False: the plane engine contains fori/while loops, which
+    # shard_map's varying-axes checker cannot rule on (the state is
     # genuinely sharded anyway)
     outs = ((sp, P("far"), P("far")) if with_served else (sp, P("far")))
-    fn = shard_map(partial(_access_body, cfg, mode, degraded, with_served),
-                   mesh=mesh, in_specs=(sp, P("far")), out_specs=outs,
-                   check_rep=False)
+    fn = jax.shard_map(
+        partial(_access_body, cfg, mode, degraded, with_served),
+        mesh=mesh, in_specs=(sp, P("far")), out_specs=outs, check_vma=False)
     return jax.jit(fn)
 
 
@@ -665,9 +677,10 @@ def _jitted_access_degmask(cfg: ShardedPlaneConfig, mode, mesh, with_served):
         return jax.jit(oracle)
     sp = _state_specs(cfg)
     outs = ((sp, P("far"), P("far")) if with_served else (sp, P("far")))
-    fn = shard_map(partial(_access_body_degmask, cfg, mode, with_served),
-                   mesh=mesh, in_specs=(sp, P("far"), P("far")),
-                   out_specs=outs, check_rep=False)
+    fn = jax.shard_map(
+        partial(_access_body_degmask, cfg, mode, with_served),
+        mesh=mesh, in_specs=(sp, P("far"), P("far")), out_specs=outs,
+        check_vma=False)
     return jax.jit(fn)
 
 
@@ -688,9 +701,9 @@ def _jitted_update(cfg: ShardedPlaneConfig, mode, mesh):
     if mesh is None:
         return jax.jit(partial(update, cfg, mode=mode))
     sp = _state_specs(cfg)
-    fn = shard_map(partial(_update_body, cfg, mode), mesh=mesh,
-                   in_specs=(sp, P("far"), P("far")), out_specs=sp,
-                   check_rep=False)
+    fn = jax.shard_map(partial(_update_body, cfg, mode), mesh=mesh,
+                       in_specs=(sp, P("far"), P("far")), out_specs=sp,
+                       check_vma=False)
     return jax.jit(fn)
 
 
@@ -703,8 +716,8 @@ def _jitted_advance_epoch(cfg: ShardedPlaneConfig, mesh):
     if mesh is None:
         return jax.jit(partial(advance_epoch, cfg))
     sp = _state_specs(cfg)
-    fn = shard_map(partial(_epoch_body, cfg), mesh=mesh, in_specs=(sp,),
-                   out_specs=sp, check_rep=False)
+    fn = jax.shard_map(partial(_epoch_body, cfg), mesh=mesh,
+                       in_specs=(sp,), out_specs=sp, check_vma=False)
     return jax.jit(fn)
 
 
@@ -721,9 +734,10 @@ def _jitted_evacuate(cfg: ShardedPlaneConfig, garbage_threshold, max_pages,
                                max_pages=max_pages,
                                clear_access=clear_access))
     sp = _state_specs(cfg)
-    fn = shard_map(partial(_evac_body, cfg, garbage_threshold, max_pages,
-                           clear_access), mesh=mesh, in_specs=(sp,),
-                   out_specs=sp, check_rep=False)
+    fn = jax.shard_map(partial(_evac_body, cfg, garbage_threshold,
+                               max_pages, clear_access),
+                       mesh=mesh, in_specs=(sp,), out_specs=sp,
+                       check_vma=False)
     return jax.jit(fn)
 
 

@@ -1,6 +1,7 @@
 """Plane state pytree and constructors."""
 from __future__ import annotations
 
+import functools
 from typing import NamedTuple
 
 import jax
@@ -152,6 +153,13 @@ def create(cfg: PlaneConfig, initial: jnp.ndarray) -> PlaneState:
         lru_hand=jnp.asarray(0, jnp.int32),
         stats=PlaneStats.zeros(),
     )
+
+
+@functools.lru_cache(maxsize=None)
+def jitted_create(cfg: PlaneConfig):
+    """``create`` as one compiled program: the slab is built in place, so
+    set-up holds one slab copy instead of the eager ops' several."""
+    return jax.jit(functools.partial(create, cfg))
 
 
 def bump(stats: PlaneStats, **deltas) -> PlaneStats:

@@ -5,10 +5,11 @@ Per-kernel modules hold the ``pl.pallas_call`` + BlockSpec implementations;
 surface used by the rest of the framework.
 
 Kernels:
-  * ``gather_objects``  — runtime-path object ingress (row gather)
+  * ``gather_objects``  — far-tier ingress: row gather (runtime path,
+    evacuator page assembly) and page gather (paging path)
+  * ``cat_decay``       — epoch-advance CAR EMA (the governor's profile)
   * ``paged_attention`` — decode attention through the page table
-  * ``cat_update``      — always-on card-table profiling + CAR popcount
-  * ``compact``         — evacuator page assembly (hot/cold segregation)
+  * ``cat_update``      — card-table update + CAR popcount (no caller)
   * ``topk_pages``      — offload-space page scoring for sparse attention
 """
 from . import ops, ref

@@ -1,8 +1,7 @@
 """Epoch-advance kernel: decayed card-access-rate EMA (always-on profiling).
 
-Sibling of ``cat_update``: where that kernel folds a batch of touches INTO
-the card table, this one folds the card table into the per-page CAR EMA at
-an epoch boundary.  Each grid step reduces one page's card bits to the
+This kernel folds the card table into the per-page CAR EMA at an epoch
+boundary.  Each grid step reduces a tile of pages' card bits to the
 epoch-window CAR (popcount / allocated cards) and blends it into the
 running EMA:
 
@@ -13,8 +12,13 @@ page's PSF from this decayed CAR — path selection adapts online instead of
 waiting for a page-out — and the caller clears the card table to open the
 next epoch window.
 
-Shapes: cat [V, P] int32 (0/1 card bits), car_ema [V, 1] float32,
-        alloc [V, 1] int32 -> new_ema [V, 1] float32
+Layout: pages run along the lanes.  The card table arrives transposed,
+``[P, V]``, and the per-page vectors as ``[1, V]`` rows, so every block is
+lane-dense; a ``[V, 1]`` column would pad each page to a 128-lane tile.
+``V`` is padded to a multiple of the page tile (a multiple of 128).
+
+Shapes: cat_t [P, V] int32 (0/1 card bits), car_ema [1, V] float32,
+        alloc [1, V] int32 -> new_ema [1, V] float32
 """
 from __future__ import annotations
 
@@ -23,11 +27,12 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
+
+MAX_PAGE_TILE = 8192     # pages per grid step (lanes)
 
 
 def _kernel(cat_ref, ema_ref, alloc_ref, out_ref, *, decay: float):
-    cnt = jnp.sum(cat_ref[...].astype(jnp.float32), axis=1, keepdims=True)
+    cnt = jnp.sum(cat_ref[...].astype(jnp.float32), axis=0, keepdims=True)
     denom = jnp.maximum(alloc_ref[...], 1).astype(jnp.float32)
     car = cnt / denom
     out_ref[...] = jnp.float32(decay) * ema_ref[...] + \
@@ -35,22 +40,23 @@ def _kernel(cat_ref, ema_ref, alloc_ref, out_ref, *, decay: float):
 
 
 @functools.partial(jax.jit, static_argnames=("decay", "interpret"))
-def cat_decay(cat: jnp.ndarray, car_ema: jnp.ndarray, alloc: jnp.ndarray, *,
-              decay: float, interpret: bool = False) -> jnp.ndarray:
-    """cat [V, P] int32, car_ema [V, 1] f32, alloc [V, 1] i32 -> [V, 1] f32."""
-    V, P = cat.shape
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=0,
-        grid=(V,),
-        in_specs=[pl.BlockSpec((1, P), lambda v: (v, 0)),
-                  pl.BlockSpec((1, 1), lambda v: (v, 0)),
-                  pl.BlockSpec((1, 1), lambda v: (v, 0))],
-        out_specs=pl.BlockSpec((1, 1), lambda v: (v, 0)),
-    )
-    kernel = functools.partial(_kernel, decay=decay)
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((V, 1), jnp.float32),
+def cat_decay(cat_t: jnp.ndarray, car_ema: jnp.ndarray, alloc: jnp.ndarray,
+              *, decay: float, interpret: bool = False) -> jnp.ndarray:
+    """cat_t [P, V] int32, car_ema [1, V] f32, alloc [1, V] i32 -> [1, V]."""
+    P, V = cat_t.shape
+    tile = min(MAX_PAGE_TILE, -(-V // 128) * 128)
+    pad = -V % tile
+    if pad:
+        cat_t = jnp.pad(cat_t, ((0, 0), (0, pad)))
+        car_ema = jnp.pad(car_ema, ((0, 0), (0, pad)))
+        alloc = jnp.pad(alloc, ((0, 0), (0, pad)))
+    row = pl.BlockSpec((1, tile), lambda v: (0, v))
+    out = pl.pallas_call(
+        functools.partial(_kernel, decay=decay),
+        grid=((V + pad) // tile,),
+        in_specs=[pl.BlockSpec((P, tile), lambda v: (0, v)), row, row],
+        out_specs=row,
+        out_shape=jax.ShapeDtypeStruct((1, V + pad), jnp.float32),
         interpret=interpret,
-    )(cat, car_ema, alloc)
+    )(cat_t, car_ema, alloc)
+    return out[:, :V]

@@ -18,7 +18,7 @@ import jax.numpy as jnp
 from . import ref
 from .cat_decay import cat_decay as _cat_decay_pallas
 from .cat_update import cat_update as _cat_pallas
-from .compact import compact_pages as _compact_pallas
+from .gather_objects import gather_pages as _gather_pages_pallas
 from .gather_objects import gather_rows as _gather_pallas
 from .paged_attention import paged_attention as _paged_attn_pallas
 from .topk_pages import page_scores as _scores_pallas
@@ -28,7 +28,9 @@ def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
-def _mode(impl: str) -> str:
+def resolve_impl(impl: str) -> str:
+    """The implementation ``impl`` runs as: ``"auto"`` resolves to
+    ``"pallas"`` on TPU and ``"ref"`` elsewhere."""
     if impl == "auto":
         return "pallas" if _on_tpu() else "ref"
     return impl
@@ -40,7 +42,7 @@ def gather_rows(pool, idx, *, impl="auto", masked=True):
     jnp path's extra select over the result) when the caller never consumes
     rows at negative indices — e.g. when they are dropped by a downstream
     masked scatter."""
-    m = _mode(impl)
+    m = resolve_impl(impl)
     if m == "ref":
         if not masked:
             return pool[jnp.maximum(idx, 0)]
@@ -49,23 +51,29 @@ def gather_rows(pool, idx, *, impl="auto", masked=True):
 
 
 def gather_pages(slab, page_ids, perm=None, *, impl="auto", masked=True):
-    """Multi-head page assembly in ONE batched row gather.
+    """Multi-head page assembly in ONE batched page gather.
 
     slab [KVH, S, P, Dh]; page_ids [N] int32 (-1 = masked, yields zero
     pages); optional perm [N, P] row permutation applied to each fetched
     page (the runtime path's hot-row packing) -> [KVH, N, P, Dh].
 
-    The pool is viewed page-granularly ([KVH*S, P*Dh]) so each fetched
-    page is ONE ``gather_rows`` row — one DMA descriptor per page per head,
-    all heads in a single kernel launch.  The packing permutation runs
-    locally on the fetched tile (egress from the far tier is always
-    page-granular; packing is a local-space relayout)."""
+    The pool is viewed page-granularly ([KVH*S, P, Dh]) so each fetched
+    page is ONE kernel block — one DMA per page per head, all heads in a
+    single kernel launch.  The packing permutation runs locally on the
+    fetched tile (egress from the far tier is always page-granular;
+    packing is a local-space relayout)."""
     KVH, S, P, Dh = slab.shape
     N = page_ids.shape[0]
     base = jnp.arange(KVH, dtype=jnp.int32)[:, None] * S
-    idx = jnp.where(page_ids[None] >= 0, base + page_ids[None], -1)
-    pages = gather_rows(slab.reshape(KVH * S, P * Dh), idx.reshape(-1),
-                        impl=impl, masked=masked).reshape(KVH, N, P, Dh)
+    idx = jnp.where(page_ids[None] >= 0, base + page_ids[None], -1).reshape(-1)
+    m = resolve_impl(impl)
+    if m == "ref":
+        pages = gather_rows(slab.reshape(KVH * S, P * Dh), idx, impl=m,
+                            masked=masked)
+    else:
+        pages = _gather_pages_pallas(slab.reshape(KVH * S, P, Dh), idx,
+                                     interpret=(m == "interpret"))
+    pages = pages.reshape(KVH, N, P, Dh)
     if perm is not None:
         pages = jnp.take_along_axis(pages, perm[None, :, :, None], axis=2)
     return pages
@@ -73,7 +81,7 @@ def gather_pages(slab, page_ids, perm=None, *, impl="auto", masked=True):
 
 def cat_update(cat_bits, vaddrs, *, page_objs: int, impl="auto"):
     """Returns (bits, car[V] float32)."""
-    m = _mode(impl)
+    m = resolve_impl(impl)
     if m == "ref":
         return ref.cat_update_ref(cat_bits, vaddrs, page_objs)
     bits, counts = _cat_pallas(cat_bits, vaddrs, page_objs=page_objs,
@@ -84,13 +92,13 @@ def cat_update(cat_bits, vaddrs, *, page_objs: int, impl="auto"):
 def cat_decay(cat, car_ema, alloc, *, decay: float, impl="auto"):
     """Epoch-advance CAR EMA.  cat [V, P] bool, car_ema [V] f32,
     alloc [V] i32 -> new_ema [V] f32 (see kernels.cat_decay)."""
-    m = _mode(impl)
+    m = resolve_impl(impl)
     cat_i = cat.astype(jnp.int32)
     if m == "ref":
         return ref.cat_decay_ref(cat_i, car_ema, alloc, decay)
-    out = _cat_decay_pallas(cat_i, car_ema[:, None], alloc[:, None],
+    out = _cat_decay_pallas(cat_i.T, car_ema[None], alloc[None],
                             decay=decay, interpret=(m == "interpret"))
-    return out[:, 0]
+    return out[0]
 
 
 def paged_attention(q, k_pages, v_pages, page_table, page_lens, *, impl="auto"):
@@ -100,7 +108,7 @@ def paged_attention(q, k_pages, v_pages, page_table, page_lens, *, impl="auto"):
     Returns (out [B, H, Dh], row_used [B, NP, P] bool) — ``row_used`` is the
     card-profiling signal: rows whose attention weight exceeded the
     within-page mean."""
-    m = _mode(impl)
+    m = resolve_impl(impl)
     if m == "ref":
         return ref.paged_attention_ref(q, k_pages, v_pages, page_table,
                                        page_lens)
@@ -122,27 +130,24 @@ def lengths_to_page_lens(lengths, num_pages: int, page_tokens: int):
 
 
 def compact_pages(pool, plan, *, page_objs: int, impl="auto"):
-    """pool [N, D], plan [M*P] flat row ids -> assembled pages [M, P, D]."""
-    m = _mode(impl)
-    if m == "ref":
-        D = pool.shape[-1]
-        M = plan.shape[0] // page_objs
-        return ref.gather_rows_ref(pool, plan).reshape(M, page_objs, D)
-    return _compact_pallas(pool, plan, page_objs=page_objs,
-                           interpret=(m == "interpret"))
+    """pool [N, D], plan [M*P] flat row ids -> assembled pages [M, P, D].
+
+    The evacuator's page assembly: each destination slot gathers its
+    source row (-1 = zero-filled slot), so it is one row gather."""
+    M = plan.shape[0] // page_objs
+    return gather_rows(pool, plan, impl=impl).reshape(M, page_objs,
+                                                      pool.shape[-1])
 
 
 def page_scores(q, kmax, kmin, *, impl="auto"):
     """q [B, H, Dh] -> scores [B, KVH, NP] float32."""
-    m = _mode(impl)
+    m = resolve_impl(impl)
     if m == "ref":
         return ref.page_scores_ref(q, kmax, kmin)
     B, H, Dh = q.shape
     KVH, NP, _ = kmax.shape
     G = H // KVH
-    blk = NP if NP < 128 else 128
-    while NP % blk:
-        blk //= 2
+    blk = 128 if NP % 128 == 0 else NP
     return _scores_pallas(q.reshape(B, KVH, G, Dh), kmax, kmin,
                           block_pages=blk, interpret=(m == "interpret"))
 

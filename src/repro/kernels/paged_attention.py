@@ -11,8 +11,11 @@ Shapes:
     k_pages     [KVH, F, P, Dh]   frame pool
     v_pages     [KVH, F, P, Dh]
     page_table  [B * NP] int32    frame id per (seq, logical page), -1 unused
-    lengths     [B] int32         live tokens per sequence
+    page_lens   [B * NP] int32    valid rows per (seq, logical page)
     out         [B, KVH, G, Dh]
+    used        [B, KVH, NP, 1, P] int8   card-profiling bits per page row
+                (the unit dim keeps each page's ``(1, P)`` block equal to
+                the array's trailing dims, as Mosaic requires)
 
 Online-softmax accumulation in f32 VMEM scratch; grid (B, KVH, NP) with the
 page dimension innermost so scratch carries across pages of one (seq, head).
@@ -103,7 +106,8 @@ def paged_attention(q: jnp.ndarray, k_pages: jnp.ndarray, v_pages: jnp.ndarray,
         ],
         out_specs=[
             pl.BlockSpec((1, 1, G, Dh), lambda b, h, j, pt, ln: (b, h, 0, 0)),
-            pl.BlockSpec((1, 1, 1, P), lambda b, h, j, pt, ln: (b, h, j, 0)),
+            pl.BlockSpec((None, None, None, 1, P),
+                         lambda b, h, j, pt, ln: (b, h, j, 0, 0)),
         ],
         scratch_shapes=[
             pltpu.VMEM((G, 1), jnp.float32),
@@ -116,7 +120,7 @@ def paged_attention(q: jnp.ndarray, k_pages: jnp.ndarray, v_pages: jnp.ndarray,
         kernel,
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((B, KVH, G, Dh), q.dtype),
-                   jax.ShapeDtypeStruct((B, KVH, NP, P), jnp.int8)],
+                   jax.ShapeDtypeStruct((B, KVH, NP, 1, P), jnp.int8)],
         interpret=interpret,
     )(page_table, page_lens, q, k_pages, v_pages)
-    return out, used
+    return out, used.reshape(B, KVH, NP, P)

@@ -9,7 +9,9 @@ score[b, h, n] = max_g sum_d max(q[b,h,g,d] * kmax[h,n,d],
                                  q[b,h,g,d] * kmin[h,n,d])
 
 Shapes: q [B, KVH, G, Dh], kmax/kmin [KVH, NP, Dh] -> scores [B, KVH, NP]
-(NP must be a multiple of the page block, default 128).
+(NP must be a multiple of the page block, default 128; the block is a
+multiple of 128 or all of NP).  The kernel writes ``[B, KVH, 1, NP]`` so
+each output block's trailing dims are ``(1, NPB)``, legal for Mosaic.
 """
 from __future__ import annotations
 
@@ -29,7 +31,7 @@ def _kernel(q_ref, kmax_ref, kmin_ref, out_ref):
     hi = q[:, None, :] * kmax[None, :, :]
     lo = q[:, None, :] * kmin[None, :, :]
     ub = jnp.maximum(hi, lo).sum(axis=-1)        # [G, NPB]
-    out_ref[0, 0] = jnp.max(ub, axis=0)          # [NPB]
+    out_ref[...] = jnp.max(ub, axis=0, keepdims=True)   # [1, NPB]
 
 
 @functools.partial(jax.jit, static_argnames=("block_pages", "interpret"))
@@ -49,7 +51,8 @@ def page_scores(q: jnp.ndarray, kmax: jnp.ndarray, kmin: jnp.ndarray, *,
             pl.BlockSpec((1, NPB, Dh), lambda b, h, n: (h, n, 0)),
             pl.BlockSpec((1, NPB, Dh), lambda b, h, n: (h, n, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, NPB), lambda b, h, n: (b, h, n)),
-        out_shape=jax.ShapeDtypeStruct((B, KVH, NP), jnp.float32),
+        out_specs=pl.BlockSpec((None, None, 1, NPB),
+                               lambda b, h, n: (b, h, 0, n)),
+        out_shape=jax.ShapeDtypeStruct((B, KVH, 1, NP), jnp.float32),
         interpret=interpret,
-    )(q, kmax, kmin)
+    )(q, kmax, kmin).reshape(B, KVH, NP)

@@ -18,7 +18,7 @@ from typing import Optional
 
 import jax
 import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
@@ -148,12 +148,16 @@ def sharding_tree(mesh: Mesh, spec_tree):
 
 
 def constrain(x, spec):
-    """Logical sharding constraint; no-op when tracing without a mesh."""
+    """Logical sharding constraint; no-op when tracing without a mesh.
+
+    The constraint names the mesh's axes as ``Auto``: ``jax.make_mesh``
+    builds ``Explicit`` axes, which a sharding constraint may not name."""
     try:
         mesh = jax._src.mesh.thread_resources.env.physical_mesh
     except Exception:
         return x
     if mesh is None or mesh.empty:
         return x
+    mesh = mesh.update(axis_types=(AxisType.Auto,) * len(mesh.axis_names))
     return jax.lax.with_sharding_constraint(
         x, NamedSharding(mesh, resolve(mesh, spec)))

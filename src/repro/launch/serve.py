@@ -23,26 +23,68 @@ import numpy as np
 from repro import configs as cfgs
 from repro.core.layout import PlaneConfig
 from repro.data import kvworkload
+from repro.launch import compile_cache
 from repro.models import api
 from repro.serving.engine import Engine, EngineConfig
 
 
-def serve_kv(args):
-    n_objs = args.objects
-    data_pages = -(-n_objs // 8)
-    pcfg = PlaneConfig(num_objs=n_objs, obj_dim=32, page_objs=8,
-                       num_frames=max(int(data_pages * args.local), 8),
+def kv_plane_config(num_objs: int, obj_dim: int,
+                    local: float) -> PlaneConfig:
+    """Store geometry: ``num_objs`` records of ``obj_dim`` f32 in 8-record
+    pages, frames for ``local`` of the data pages, and a virtual page space
+    three times the data (log headroom for the runtime path and the
+    evacuator)."""
+    data_pages = -(-num_objs // 8)
+    return PlaneConfig(num_objs=num_objs, obj_dim=obj_dim, page_objs=8,
+                       num_frames=max(int(data_pages * local), 8),
                        num_vpages=3 * data_pages, readahead=2)
-    data = jnp.arange(n_objs * 32, dtype=jnp.float32).reshape(n_objs, 32)
-    eng = Engine(EngineConfig(plane=args.plane, batch=args.batch), pcfg, data)
-    wl = kvworkload.WORKLOADS[args.workload](n_objs, args.batch, args.steps,
-                                             seed=0)
-    rep = eng.run(wl, offered_interarrival_s=args.interarrival)
+
+
+def make_records(num_objs: int, obj_dim: int, seed: int) -> np.ndarray:
+    """Host-side record values ``[num_objs, obj_dim]`` f32 from ``seed``."""
+    rng = np.random.default_rng(seed)
+    return rng.random((num_objs, obj_dim), dtype=np.float32)
+
+
+def serve_kv(ecfg: EngineConfig, pcfg: PlaneConfig, data: np.ndarray,
+             workload, *, interarrival: float = 0.0, mesh=None):
+    """Serve ``workload`` (batches of record ids) through an ``Engine``
+    holding ``data`` and check every served row against ``data[ids]``.
+
+    Returns ``(report, engine)``: the engine's run report plus
+    ``setup_s`` (state build and warm-up compiles), ``requests`` and
+    ``mismatches`` (served rows that differ from the data)."""
+    t0 = time.time()
+    eng = Engine(ecfg, pcfg, data, mesh=mesh)
+    jax.block_until_ready(eng.state)
+    setup_s = time.time() - t0
+    served = []
+    rep = eng.run(workload, offered_interarrival_s=interarrival,
+                  on_batch=lambda ids, rows: served.append((ids, rows)))
+    ids = np.concatenate([np.asarray(i, np.int64) for i, _ in served])
+    rows = np.concatenate([np.asarray(r) for _, r in served])
+    bad = np.any(rows != data[ids], axis=1)
+    rep.update(setup_s=setup_s, requests=int(ids.size),
+               mismatches=int(bad.sum()))
+    return rep, eng
+
+
+def main_kv(args):
+    pcfg = kv_plane_config(args.objects, args.obj_dim, args.local)
+    data = make_records(args.objects, args.obj_dim, args.seed)
+    wl = kvworkload.WORKLOADS[args.workload](args.objects, args.batch,
+                                             args.steps, seed=args.seed)
+    rep, _ = serve_kv(EngineConfig(plane=args.plane, batch=args.batch),
+                      pcfg, data, wl, interarrival=args.interarrival)
     print(f"[serve:kv] plane={args.plane} workload={args.workload} "
           f"local={args.local:.0%}")
     print(f"  latency: {rep['latency']}")
     print(f"  stats:   {rep['stats']}")
     print(f"  paging fraction: {rep['paging_fraction']:.2f}")
+    print(f"  verified: {rep['requests'] - rep['mismatches']}/"
+          f"{rep['requests']} rows match the data")
+    if rep["mismatches"]:
+        raise SystemExit(f"{rep['mismatches']} served rows differ from data")
 
 
 def serve_lm(args):
@@ -77,16 +119,20 @@ def main():
     p.add_argument("--workload", default="mcd_cl",
                    choices=list(kvworkload.WORKLOADS))
     p.add_argument("--objects", type=int, default=4096)
+    p.add_argument("--obj-dim", type=int, default=32,
+                   help="record width in f32 elements")
     p.add_argument("--local", type=float, default=0.25)
     p.add_argument("--steps", type=int, default=100)
     p.add_argument("--batch", type=int, default=64)
     p.add_argument("--interarrival", type=float, default=0.0)
+    p.add_argument("--seed", type=int, default=0)
     # lm mode
     p.add_argument("--arch", default="llama3-8b")
     p.add_argument("--tokens", type=int, default=32)
     args = p.parse_args()
+    compile_cache.enable()
     if args.mode == "kv":
-        serve_kv(args)
+        main_kv(args)
     else:
         serve_lm(args)
 

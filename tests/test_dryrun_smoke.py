@@ -37,8 +37,6 @@ with mesh:
                            {k: v[0] for k, v in bs.items()})
     compiled = lowered.compile()
 ca = compiled.cost_analysis()
-if isinstance(ca, (list, tuple)):   # older jax returns one dict per device
-    ca = ca[0]
 from repro.analysis import hlo
 coll = hlo.collective_summary(compiled.as_text())
 print(json.dumps({"flops": ca.get("flops", 0),

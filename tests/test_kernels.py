@@ -7,7 +7,6 @@ import pytest
 
 from repro.kernels import ops, ref
 from repro.kernels.cat_update import cat_update as cat_pallas
-from repro.kernels.compact import compact_pages as compact_pallas
 from repro.kernels.gather_objects import gather_rows as gather_pallas
 from repro.kernels.paged_attention import paged_attention as pattn_pallas
 from repro.kernels.topk_pages import page_scores as scores_pallas
@@ -16,13 +15,26 @@ RNG = np.random.RandomState(0)
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-@pytest.mark.parametrize("n,d,r", [(16, 128, 4), (64, 256, 17), (8, 512, 8)])
+@pytest.mark.parametrize("n,d,r", [(16, 128, 4), (64, 256, 17), (8, 512, 8),
+                                   (13, 128, 9)])
 def test_gather_sweep(n, d, r, dtype):
     pool = jnp.asarray(RNG.randn(n, d), dtype)
     idx = jnp.asarray(RNG.randint(-1, n, size=r), jnp.int32)
     out = gather_pallas(pool, idx, interpret=True)
     expect = ref.gather_rows_ref(pool, idx)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(expect))
+
+
+@pytest.mark.parametrize("kvh,s,p,d,n", [(1, 12, 8, 256, 5), (2, 6, 16, 128, 4)])
+def test_gather_pages_sweep(kvh, s, p, d, n):
+    slab = jnp.asarray(RNG.randn(kvh, s, p, d), jnp.float32)
+    ids = jnp.asarray(RNG.randint(-1, s, size=n), jnp.int32)
+    perm = jnp.asarray(np.stack([RNG.permutation(p) for _ in range(n)]),
+                       jnp.int32)
+    for pm in (None, perm):
+        got = ops.gather_pages(slab, ids, pm, impl="interpret")
+        expect = ops.gather_pages(slab, ids, pm, impl="ref")
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(expect))
 
 
 @pytest.mark.parametrize("v,p,r", [(4, 32, 5), (8, 64, 16), (3, 96, 1)])
@@ -83,7 +95,7 @@ def test_paged_attention_sweep(b, kvh, g, dh, f, p, npg, dtype):
 def test_compact_sweep(f, p, d, m):
     pool = jnp.asarray(RNG.randn(f * p, d), jnp.float32)
     plan = jnp.asarray(RNG.randint(-1, f * p, size=m * p), jnp.int32)
-    got = compact_pallas(pool, plan, page_objs=p, interpret=True)
+    got = ops.compact_pages(pool, plan, page_objs=p, impl="interpret")
     expect = ops.compact_pages(pool, plan, page_objs=p, impl="ref")
     np.testing.assert_array_equal(np.asarray(got), np.asarray(expect))
 
