@@ -1,0 +1,11 @@
+"""Device time (ms) per tick of the programs that run less often than once
+a tick (``evacuate``, ``advance_epoch``), in the traced stretch."""
+import os
+import sys
+
+sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
+import _ticks  # noqa: E402
+
+
+def read(rec):
+    return _ticks.per_tick_ms(rec, "maint")
