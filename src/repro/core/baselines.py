@@ -21,7 +21,6 @@ that object-granular egress serializes on metadata scans.
 from __future__ import annotations
 
 import functools
-from functools import partial
 
 import jax
 import jax.numpy as jnp
@@ -185,7 +184,7 @@ def object_access(cfg: PlaneConfig, s: st.PlaneState, obj_ids: jnp.ndarray,
 
 @functools.lru_cache(maxsize=None)
 def _jitted_paging_access(cfg: PlaneConfig, mode: str):
-    return jax.jit(partial(paging_access, cfg, mode=mode))
+    return jax.jit(st.named_partial(paging_access, cfg, mode=mode))
 
 
 def jitted_paging_access(cfg: PlaneConfig, mode: str | None = None):
@@ -194,7 +193,7 @@ def jitted_paging_access(cfg: PlaneConfig, mode: str | None = None):
 
 @functools.lru_cache(maxsize=None)
 def _jitted_object_access(cfg: PlaneConfig, mode: str):
-    return jax.jit(partial(object_access, cfg, mode=mode))
+    return jax.jit(st.named_partial(object_access, cfg, mode=mode))
 
 
 def jitted_object_access(cfg: PlaneConfig, mode: str | None = None):
@@ -206,8 +205,8 @@ def jitted_object_access(cfg: PlaneConfig, mode: str | None = None):
 
 @functools.lru_cache(maxsize=None)
 def _jitted_plan_paging(cfg: PlaneConfig, degraded: bool):
-    return jax.jit(partial(batch_lib.plan_access, cfg, split_by_psf=False,
-                           degraded=degraded))
+    return jax.jit(st.named_partial(batch_lib.plan_access, cfg,
+                                    split_by_psf=False, degraded=degraded))
 
 
 def jitted_plan_paging(cfg: PlaneConfig, degraded: bool = False):
@@ -216,7 +215,8 @@ def jitted_plan_paging(cfg: PlaneConfig, degraded: bool = False):
 
 @functools.lru_cache(maxsize=None)
 def _jitted_execute_paging(cfg: PlaneConfig, mode: str):
-    return jax.jit(partial(batch_lib.execute_paging_access, cfg, mode=mode))
+    return jax.jit(st.named_partial(batch_lib.execute_paging_access, cfg,
+                                    mode=mode))
 
 
 def jitted_execute_paging(cfg: PlaneConfig, mode: str | None = None):
@@ -225,8 +225,8 @@ def jitted_execute_paging(cfg: PlaneConfig, mode: str | None = None):
 
 @functools.lru_cache(maxsize=None)
 def _jitted_plan_object(cfg: PlaneConfig, degraded: bool):
-    return jax.jit(partial(batch_lib.plan_access, cfg, all_runtime=True,
-                           degraded=degraded))
+    return jax.jit(st.named_partial(batch_lib.plan_access, cfg,
+                                    all_runtime=True, degraded=degraded))
 
 
 def jitted_plan_object(cfg: PlaneConfig, degraded: bool = False):
@@ -235,8 +235,8 @@ def jitted_plan_object(cfg: PlaneConfig, degraded: bool = False):
 
 @functools.lru_cache(maxsize=None)
 def _jitted_execute_object(cfg: PlaneConfig, mode: str):
-    return jax.jit(partial(batch_lib.execute_object_access, cfg, mode=mode,
-                           reclaim=object_reclaim))
+    return jax.jit(st.named_partial(batch_lib.execute_object_access, cfg,
+                                    mode=mode, reclaim=object_reclaim))
 
 
 def jitted_execute_object(cfg: PlaneConfig, mode: str | None = None):

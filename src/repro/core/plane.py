@@ -19,7 +19,6 @@ through ``ops.compact_pages``, one batched row gather.
 from __future__ import annotations
 
 import functools
-from functools import partial
 from typing import NamedTuple
 
 import jax
@@ -74,11 +73,12 @@ def update(cfg: PlaneConfig, s: st.PlaneState, obj_ids: jnp.ndarray,
 # engine/test/benchmark in a process shares one compilation per config.
 # The thin wrappers normalize defaulted arguments before the cache lookup
 # (lru_cache keys raw call args, so ``f(cfg)`` and ``f(cfg, "batch")``
-# would otherwise compile twice).
+# would otherwise compile twice).  Each program is compiled under its entry
+# point's name (``st.named_partial``): ``jit_plan_access`` and so on.
 
 @functools.lru_cache(maxsize=None)
 def _jitted_access(cfg: PlaneConfig, mode: str):
-    return jax.jit(partial(access, cfg, mode=mode))
+    return jax.jit(st.named_partial(access, cfg, mode=mode))
 
 
 def jitted_access(cfg: PlaneConfig, mode: str | None = None):
@@ -87,7 +87,7 @@ def jitted_access(cfg: PlaneConfig, mode: str | None = None):
 
 @functools.lru_cache(maxsize=None)
 def _jitted_update(cfg: PlaneConfig, mode: str):
-    return jax.jit(partial(update, cfg, mode=mode))
+    return jax.jit(st.named_partial(update, cfg, mode=mode))
 
 
 def jitted_update(cfg: PlaneConfig, mode: str | None = None):
@@ -100,7 +100,8 @@ def jitted_update(cfg: PlaneConfig, mode: str | None = None):
 
 @functools.lru_cache(maxsize=None)
 def _jitted_plan_access(cfg: PlaneConfig, degraded: bool):
-    return jax.jit(partial(batch_lib.plan_access, cfg, degraded=degraded))
+    return jax.jit(st.named_partial(batch_lib.plan_access, cfg,
+                                    degraded=degraded))
 
 
 def jitted_plan_access(cfg: PlaneConfig, degraded: bool = False):
@@ -109,7 +110,8 @@ def jitted_plan_access(cfg: PlaneConfig, degraded: bool = False):
 
 @functools.lru_cache(maxsize=None)
 def _jitted_execute_access(cfg: PlaneConfig, mode: str):
-    return jax.jit(partial(batch_lib.execute_access, cfg, mode=mode))
+    return jax.jit(st.named_partial(batch_lib.execute_access, cfg,
+                                    mode=mode))
 
 
 def jitted_execute_access(cfg: PlaneConfig, mode: str | None = None):
@@ -119,8 +121,9 @@ def jitted_execute_access(cfg: PlaneConfig, mode: str | None = None):
 @functools.lru_cache(maxsize=None)
 def _jitted_evacuate(cfg: PlaneConfig, garbage_threshold: float | None,
                      max_pages: int, clear_access: bool):
-    return jax.jit(partial(evacuate, cfg, garbage_threshold=garbage_threshold,
-                           max_pages=max_pages, clear_access=clear_access))
+    return jax.jit(st.named_partial(
+        evacuate, cfg, garbage_threshold=garbage_threshold,
+        max_pages=max_pages, clear_access=clear_access))
 
 
 def jitted_evacuate(cfg: PlaneConfig, garbage_threshold: float | None = None,
@@ -131,9 +134,9 @@ def jitted_evacuate(cfg: PlaneConfig, garbage_threshold: float | None = None,
 @functools.lru_cache(maxsize=None)
 def _jitted_plan_evacuate(cfg: PlaneConfig, garbage_threshold: float | None,
                           max_pages: int):
-    return jax.jit(partial(plan_evacuate, cfg,
-                           garbage_threshold=garbage_threshold,
-                           max_pages=max_pages))
+    return jax.jit(st.named_partial(plan_evacuate, cfg,
+                                    garbage_threshold=garbage_threshold,
+                                    max_pages=max_pages))
 
 
 def jitted_plan_evacuate(cfg: PlaneConfig,
@@ -146,9 +149,9 @@ def jitted_plan_evacuate(cfg: PlaneConfig,
 def _jitted_execute_evacuate(cfg: PlaneConfig,
                              garbage_threshold: float | None,
                              clear_access: bool):
-    return jax.jit(partial(execute_evacuate, cfg,
-                           garbage_threshold=garbage_threshold,
-                           clear_access=clear_access))
+    return jax.jit(st.named_partial(execute_evacuate, cfg,
+                                    garbage_threshold=garbage_threshold,
+                                    clear_access=clear_access))
 
 
 def jitted_execute_evacuate(cfg: PlaneConfig,
@@ -159,7 +162,7 @@ def jitted_execute_evacuate(cfg: PlaneConfig,
 
 @functools.lru_cache(maxsize=None)
 def _jitted_advance_epoch(cfg: PlaneConfig):
-    return jax.jit(partial(advance_epoch, cfg))
+    return jax.jit(st.named_partial(advance_epoch, cfg))
 
 
 def jitted_advance_epoch(cfg: PlaneConfig):

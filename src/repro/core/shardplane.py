@@ -155,9 +155,9 @@ def jitted_create(cfg: ShardedPlaneConfig, mesh=None):
     arrive split over ``far`` and every output leaf is laid out
     ``P("far")``, so each device builds only its own shard's state."""
     if mesh is None:
-        return jax.jit(partial(create, cfg))
+        return jax.jit(st.named_partial(create, cfg))
     far = NamedSharding(mesh, P("far"))
-    return jax.jit(partial(create, cfg), in_shardings=far,
+    return jax.jit(st.named_partial(create, cfg), in_shardings=far,
                    out_shardings=jax.tree.map(lambda _: far,
                                               _state_specs(cfg)))
 
@@ -623,15 +623,24 @@ def jitted_phase_probe(cfg: ShardedPlaneConfig, phase: str, mesh):
     then ``"ingress"`` (pack + fused collective), then a full access gives
     the subtractive pack / collective / serve wall-share breakdown."""
     assert phase in ("pack", "ingress"), phase
-    fn = jax.shard_map(partial(_probe_body, cfg, phase), mesh=mesh,
-                       in_specs=(P("far"),), out_specs=P("far"),
-                       check_vma=False)
-    return jax.jit(fn)
+    return _jit_on_mesh("phase_probe", partial(_probe_body, cfg, phase),
+                        mesh, (P("far"),), P("far"))
 
 
 # --------------------------------------------------------------------------
 # memoized jit entry points (mesh=None -> the single-device oracle)
 # --------------------------------------------------------------------------
+
+def _jit_on_mesh(name: str, body, mesh, in_specs, out_specs):
+    """``body`` run per shard on the ``far`` mesh as one program, compiled
+    as ``jit_<name>``.  check_vma=False: the plane engine contains
+    fori/while loops, which shard_map's varying-axes checker cannot rule
+    on (the state is genuinely sharded anyway)."""
+    fn = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                       out_specs=out_specs, check_vma=False)
+    fn.__name__ = name
+    return jax.jit(fn)
+
 
 def _state_specs(cfg: ShardedPlaneConfig):
     init = jax.ShapeDtypeStruct((cfg.num_objs, cfg.shard.obj_dim),
@@ -644,17 +653,15 @@ def _state_specs(cfg: ShardedPlaneConfig):
 def _jitted_access(cfg: ShardedPlaneConfig, mode, mesh, with_served,
                    degraded):
     if mesh is None:
-        return jax.jit(partial(access, cfg, mode=mode, degraded=degraded,
-                               with_served=with_served))
+        return jax.jit(st.named_partial(access, cfg, mode=mode,
+                                        degraded=degraded,
+                                        with_served=with_served))
     sp = _state_specs(cfg)
-    # check_vma=False: the plane engine contains fori/while loops, which
-    # shard_map's varying-axes checker cannot rule on (the state is
-    # genuinely sharded anyway)
     outs = ((sp, P("far"), P("far")) if with_served else (sp, P("far")))
-    fn = jax.shard_map(
+    return _jit_on_mesh(
+        "sharded_access",
         partial(_access_body, cfg, mode, degraded, with_served),
-        mesh=mesh, in_specs=(sp, P("far")), out_specs=outs, check_vma=False)
-    return jax.jit(fn)
+        mesh, (sp, P("far")), outs)
 
 
 def jitted_access(cfg: ShardedPlaneConfig, mode=None, mesh=None, *,
@@ -671,17 +678,16 @@ def jitted_access(cfg: ShardedPlaneConfig, mode=None, mesh=None, *,
 @functools.lru_cache(maxsize=None)
 def _jitted_access_degmask(cfg: ShardedPlaneConfig, mode, mesh, with_served):
     if mesh is None:
-        def oracle(states, ids, deg):
+        def access_degmask(states, ids, deg):
             return access(cfg, states, ids, mode=mode, degraded=deg,
                           with_served=with_served)
-        return jax.jit(oracle)
+        return jax.jit(access_degmask)
     sp = _state_specs(cfg)
     outs = ((sp, P("far"), P("far")) if with_served else (sp, P("far")))
-    fn = jax.shard_map(
+    return _jit_on_mesh(
+        "sharded_access_degmask",
         partial(_access_body_degmask, cfg, mode, with_served),
-        mesh=mesh, in_specs=(sp, P("far"), P("far")), out_specs=outs,
-        check_vma=False)
-    return jax.jit(fn)
+        mesh, (sp, P("far"), P("far")), outs)
 
 
 def jitted_access_degmask(cfg: ShardedPlaneConfig, mode=None, mesh=None, *,
@@ -699,12 +705,10 @@ def jitted_access_degmask(cfg: ShardedPlaneConfig, mode=None, mesh=None, *,
 @functools.lru_cache(maxsize=None)
 def _jitted_update(cfg: ShardedPlaneConfig, mode, mesh):
     if mesh is None:
-        return jax.jit(partial(update, cfg, mode=mode))
+        return jax.jit(st.named_partial(update, cfg, mode=mode))
     sp = _state_specs(cfg)
-    fn = jax.shard_map(partial(_update_body, cfg, mode), mesh=mesh,
-                       in_specs=(sp, P("far"), P("far")), out_specs=sp,
-                       check_vma=False)
-    return jax.jit(fn)
+    return _jit_on_mesh("sharded_update", partial(_update_body, cfg, mode),
+                        mesh, (sp, P("far"), P("far")), sp)
 
 
 def jitted_update(cfg: ShardedPlaneConfig, mode=None, mesh=None):
@@ -714,11 +718,10 @@ def jitted_update(cfg: ShardedPlaneConfig, mode=None, mesh=None):
 @functools.lru_cache(maxsize=None)
 def _jitted_advance_epoch(cfg: ShardedPlaneConfig, mesh):
     if mesh is None:
-        return jax.jit(partial(advance_epoch, cfg))
+        return jax.jit(st.named_partial(advance_epoch, cfg))
     sp = _state_specs(cfg)
-    fn = jax.shard_map(partial(_epoch_body, cfg), mesh=mesh,
-                       in_specs=(sp,), out_specs=sp, check_vma=False)
-    return jax.jit(fn)
+    return _jit_on_mesh("sharded_advance_epoch", partial(_epoch_body, cfg),
+                        mesh, (sp,), sp)
 
 
 def jitted_advance_epoch(cfg: ShardedPlaneConfig, mesh=None):
@@ -729,16 +732,15 @@ def jitted_advance_epoch(cfg: ShardedPlaneConfig, mesh=None):
 def _jitted_evacuate(cfg: ShardedPlaneConfig, garbage_threshold, max_pages,
                      clear_access, mesh):
     if mesh is None:
-        return jax.jit(partial(evacuate, cfg,
-                               garbage_threshold=garbage_threshold,
-                               max_pages=max_pages,
-                               clear_access=clear_access))
+        return jax.jit(st.named_partial(evacuate, cfg,
+                                        garbage_threshold=garbage_threshold,
+                                        max_pages=max_pages,
+                                        clear_access=clear_access))
     sp = _state_specs(cfg)
-    fn = jax.shard_map(partial(_evac_body, cfg, garbage_threshold,
-                               max_pages, clear_access),
-                       mesh=mesh, in_specs=(sp,), out_specs=sp,
-                       check_vma=False)
-    return jax.jit(fn)
+    return _jit_on_mesh("sharded_evacuate",
+                        partial(_evac_body, cfg, garbage_threshold,
+                                max_pages, clear_access),
+                        mesh, (sp,), sp)
 
 
 def jitted_evacuate(cfg: ShardedPlaneConfig, garbage_threshold=None,

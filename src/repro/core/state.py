@@ -155,11 +155,22 @@ def create(cfg: PlaneConfig, initial: jnp.ndarray) -> PlaneState:
     )
 
 
+def named_partial(fn, *args, **kwargs):
+    """``functools.partial(fn, *args, **kwargs)`` under ``fn``'s name, so
+    the program ``jax.jit`` compiles from it is ``jit_<name>`` in a profile
+    (a bare partial compiles to ``jit__unknown``).  Only the name is
+    copied: ``functools.update_wrapper`` would also hand ``jax.jit`` the
+    signature of ``fn`` with its bound arguments still in it."""
+    p = functools.partial(fn, *args, **kwargs)
+    p.__name__ = fn.__name__
+    return p
+
+
 @functools.lru_cache(maxsize=None)
 def jitted_create(cfg: PlaneConfig):
     """``create`` as one compiled program: the slab is built in place, so
     set-up holds one slab copy instead of the eager ops' several."""
-    return jax.jit(functools.partial(create, cfg))
+    return jax.jit(named_partial(create, cfg))
 
 
 def bump(stats: PlaneStats, **deltas) -> PlaneStats:

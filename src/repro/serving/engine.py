@@ -51,6 +51,20 @@ and the engine closes the loop host-side:
   legacy engine-wide decision (one summed fraction trips every shard at
   once) for comparison.
 
+Host spans: each step of ``submit`` runs inside a
+``jax.profiler.TraceAnnotation``, so a profile lays the engine's host
+work on the device trace's clock and each idle gap of the device can be
+put down to the step the host was in.  ``engine.submit`` (with its
+``tick``) holds ``engine.retire`` (and its ``engine.wait`` on the
+device), ``engine.upload`` (the ids to the device), ``engine.plan`` and
+``engine.execute`` (one device) or ``engine.access`` (the fused sharded
+call), ``engine.maintenance`` (with ``engine.evacuate`` and
+``engine.epoch`` on the ticks they dispatch) and, on the robust path,
+``engine.breaker``.  No span is traced into a program, and with no
+profiler running a span costs only its enter and exit.  Every program
+the engine dispatches is compiled under its entry point's name
+(``jit_plan_access``, ``jit_execute_access``, ...).
+
 ``run`` then reports **goodput** (requests actually served) separately
 from raw throughput (served + shed) — the split the fault-window
 benchmarks plot (benchmarks/fig_faults.py).
@@ -64,11 +78,13 @@ from __future__ import annotations
 import dataclasses
 import time
 from collections import deque
+from functools import partial
 from typing import Iterable, NamedTuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation, annotate_function
 
 from repro.core import baselines, plane as plane_lib, shardplane
 from repro.core.layout import PlaneConfig
@@ -353,11 +369,14 @@ class Engine:
             # the same deltas advance_epoch profiles; sharded states sum
             # elementwise over the stacked [S] counters
             pb, rb = float(tcfg.page_bytes), float(tcfg.row_bytes)
-            self._traffic = jax.jit(lambda s: jnp.sum(
-                (s.stats.page_ins - s.epoch_page_ins).astype(jnp.float32)
-                * pb
-                + (s.stats.obj_ins - s.epoch_obj_ins).astype(jnp.float32)
-                * rb))
+
+            def epoch_traffic(s):
+                return jnp.sum(
+                    (s.stats.page_ins - s.epoch_page_ins).astype(jnp.float32)
+                    * pb
+                    + (s.stats.obj_ins - s.epoch_obj_ins).astype(jnp.float32)
+                    * rb)
+            self._traffic = jax.jit(epoch_traffic)
         if breaker_on:
             # health probe: cumulative (failed, attempted) remote fetches,
             # kept PER SHARD ([2, shards]; the unsharded plane is one
@@ -368,12 +387,15 @@ class Engine:
             # good probe.  The per-shard columns drive the per-shard trip
             # decision (``breaker_scope="shard"``); ``"global"`` sums them
             # back into the legacy engine-wide signal.
-            self._health = jax.jit(lambda s: jnp.stack([
-                jnp.atleast_1d(s.stats.fetch_failures
-                               ).astype(jnp.float32),
-                jnp.atleast_1d(s.stats.page_ins + s.stats.obj_ins
-                               + s.stats.fetch_failures
-                               ).astype(jnp.float32)]))
+
+            def fetch_health(s):
+                return jnp.stack([
+                    jnp.atleast_1d(s.stats.fetch_failures
+                                   ).astype(jnp.float32),
+                    jnp.atleast_1d(s.stats.page_ins + s.stats.obj_ins
+                                   + s.stats.fetch_failures
+                                   ).astype(jnp.float32)])
+            self._health = jax.jit(fetch_health)
         self._probe = None              # in-flight traffic watermark read
         self._hprobe = None             # in-flight health probe read
         self._hlast = np.zeros((2, cfg.shards), np.float64)
@@ -441,44 +463,52 @@ class Engine:
         from here; defaults to now).  Blocks only when more than
         ``pipeline_depth`` batches are in flight (back-pressure), never on
         the batch being submitted."""
-        t_sched = time.time() if t_sched is None else t_sched
-        # opportunistic retirement: anything already finished on device is
-        # recorded now, so recorded latency tracks actual completion rather
-        # than when back-pressure forces a block
-        while self._inflight and self._inflight[0].rows.is_ready():
-            self._retire_one()
-        if self._robust:
-            rows = self._submit_robust(obj_ids, t_sched)
-        else:
-            rows = self._dispatch(obj_ids, t_sched)
-        self.ticks += 1
-        self._maintenance()
-        limit = 0 if self.cfg.dispatch == "sync" else self.cfg.pipeline_depth
-        while len(self._inflight) > limit:
-            self._retire_one()
-        return rows
+        with TraceAnnotation("engine.submit", tick=self.ticks + 1):
+            t_sched = time.time() if t_sched is None else t_sched
+            # opportunistic retirement: anything already finished on device
+            # is recorded now, so recorded latency tracks actual completion
+            # rather than when back-pressure forces a block
+            while self._inflight and self._inflight[0].rows.is_ready():
+                self._retire_one()
+            if self._robust:
+                rows = self._submit_robust(obj_ids, t_sched)
+            else:
+                rows = self._dispatch(obj_ids, t_sched)
+            self.ticks += 1
+            self._maintenance()
+            limit = (0 if self.cfg.dispatch == "sync"
+                     else self.cfg.pipeline_depth)
+            while len(self._inflight) > limit:
+                self._retire_one()
+            return rows
 
     def _dispatch(self, obj_ids, t_sched):
         """Fault-free dispatch (the original engine path)."""
         cfg = self.cfg
-        ids = jnp.asarray(obj_ids, jnp.int32)
         n = len(obj_ids)
-        # short batches pad with the plane's negative-id no-ops: fixed
-        # shapes keep one compiled program per engine (sharded and
-        # unsharded alike)
-        if n < cfg.batch:
-            ids = jnp.concatenate(
-                [ids, jnp.full((cfg.batch - n,), -1, jnp.int32)])
+        with TraceAnnotation("engine.upload"):
+            ids = jnp.asarray(obj_ids, jnp.int32)
+            # short batches pad with the plane's negative-id no-ops: fixed
+            # shapes keep one compiled program per engine (sharded and
+            # unsharded alike)
+            if n < cfg.batch:
+                ids = jnp.concatenate(
+                    [ids, jnp.full((cfg.batch - n,), -1, jnp.int32)])
+            if self._access is not None:
+                # sharded far tier: the batch splits evenly across source
+                # shards
+                ids = ids.reshape(cfg.shards, cfg.batch // cfg.shards)
         if self._access is not None:
-            # sharded far tier: the batch splits evenly across source shards
-            S, R = cfg.shards, cfg.batch // cfg.shards
-            self.state, out = self._access(self.state, ids.reshape(S, R))
-            rows_full = out.reshape(cfg.batch, -1)
+            with TraceAnnotation("engine.access"):
+                self.state, out = self._access(self.state, ids)
+                rows_full = out.reshape(cfg.batch, -1)
         else:
             # two async device calls: the plan dispatch is what a sharded
             # deployment runs host-side / on a prefetch stream
-            plan = self._plan(self.state, ids)
-            self.state, rows_full = self._exec(self.state, ids, plan)
+            with TraceAnnotation("engine.plan"):
+                plan = self._plan(self.state, ids)
+            with TraceAnnotation("engine.execute"):
+                self.state, rows_full = self._exec(self.state, ids, plan)
         self._inflight.append(_Inflight(rows_full, t_sched, n))
         return rows_full[:n] if n < cfg.batch else rows_full
 
@@ -541,21 +571,27 @@ class Engine:
                 and tick % cfg.breaker_probe_every != 0):
             dmask = self.breaker_open_shards.copy()
             self.counters["degraded_ticks"] += int(dmask.sum())
-        ids = jnp.asarray(full)
-        if self._access is not None:
-            S, R = cfg.shards, cfg.batch // cfg.shards
+        with TraceAnnotation("engine.upload"):
+            ids = jnp.asarray(full)
+            if self._access is not None:
+                ids = ids.reshape(cfg.shards, cfg.batch // cfg.shards)
             if self._access_degmask is not None:
-                self.state, out, sv = self._access_degmask(
-                    self.state, ids.reshape(S, R), jnp.asarray(dmask))
-            else:
-                self.state, out, sv = self._access(self.state,
-                                                   ids.reshape(S, R))
-            rows_full = out.reshape(cfg.batch, -1)
-            served = sv.reshape(cfg.batch)
+                deg = jnp.asarray(dmask)
+        if self._access is not None:
+            with TraceAnnotation("engine.access"):
+                if self._access_degmask is not None:
+                    self.state, out, sv = self._access_degmask(
+                        self.state, ids, deg)
+                else:
+                    self.state, out, sv = self._access(self.state, ids)
+                rows_full = out.reshape(cfg.batch, -1)
+                served = sv.reshape(cfg.batch)
         else:
-            plan = (self._plan_deg if dmask[0] else self._plan)(
-                self.state, ids)
-            self.state, rows_full = self._exec(self.state, ids, plan)
+            with TraceAnnotation("engine.plan"):
+                plan = (self._plan_deg if dmask[0] else self._plan)(
+                    self.state, ids)
+            with TraceAnnotation("engine.execute"):
+                self.state, rows_full = self._exec(self.state, ids, plan)
             served = plan.served
         self._inflight.append(_Inflight(rows_full, t_sched, n,
                                         served, full, t0s, att))
@@ -565,6 +601,7 @@ class Engine:
             return jnp.zeros((n, rows_full.shape[1]), rows_full.dtype)
         return rows_full[:n] if n < cfg.batch else rows_full
 
+    @partial(annotate_function, name="engine.maintenance")
     def _maintenance(self):
         """Per-tick background work (evacuation slices, epoch governor)."""
         if self._evac is not None:
@@ -581,17 +618,20 @@ class Engine:
                     # first slice of each new round (period need not
                     # divide evac_every)
                     round_id = self.ticks // self.cfg.evac_every
-                    if round_id > self._evac_round:
-                        self._evac_round = round_id
-                        self.state = self._evac_slice_clear(self.state)
-                    else:
-                        self.state = self._evac_slice(self.state)
+                    with TraceAnnotation("engine.evacuate"):
+                        if round_id > self._evac_round:
+                            self._evac_round = round_id
+                            self.state = self._evac_slice_clear(self.state)
+                        else:
+                            self.state = self._evac_slice(self.state)
                     self.counters["evac_calls"] += 1
             elif self.ticks % self.cfg.evac_every == 0:
-                self.state = self._evac(self.state)
+                with TraceAnnotation("engine.evacuate"):
+                    self.state = self._evac(self.state)
                 self.counters["evac_calls"] += 1
         if self._epoch is not None and self._epoch_due():
-            self.state = self._epoch(self.state)
+            with TraceAnnotation("engine.epoch"):
+                self.state = self._epoch(self.state)
             self._probe = None          # watermark restarts from the epoch
 
     def _epoch_due(self) -> bool:
@@ -616,6 +656,7 @@ class Engine:
             return due
         return False
 
+    @partial(annotate_function, name="engine.breaker")
     def _breaker_step(self):
         """Async circuit-breaker update — same non-blocking shape as
         ``_epoch_due``: start a cumulative (failures, attempts) probe,
@@ -669,6 +710,7 @@ class Engine:
                    & (frac <= thr * hys))
         self.breaker_open_shards &= ~closing
 
+    @partial(annotate_function, name="engine.wait")
     def _wait_ready(self, rows):
         """Block on a device result, with a watchdog: a wedged device call
         raises ``TimeoutError`` after ``watchdog_s`` instead of hanging
@@ -686,6 +728,7 @@ class Engine:
             time.sleep(5e-5)
         rows.block_until_ready()
 
+    @partial(annotate_function, name="engine.retire")
     def _retire_one(self):
         e = self._inflight.popleft()
         # block only on the result actually being returned to a client
