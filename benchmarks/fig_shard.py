@@ -120,7 +120,11 @@ def run(quick: bool = False):
                     t_ing = per_call_us(
                         shardplane.jitted_phase_probe(eng.scfg, "ingress",
                                                       mesh), ids2d)
-                    t_full = per_call_us(eng._access, eng.state, ids2d)
+                    # the library's access program: the engine's own
+                    # donates its input state, which this loop reuses
+                    t_full = per_call_us(
+                        shardplane.jitted_access(eng.scfg, eng.cfg.mode,
+                                                 mesh), eng.state, ids2d)
                     pack = min(t_pack, t_full) / t_full
                     coll = min(max(t_ing - t_pack, 0.0), t_full) / t_full
                     serve = max(1.0 - pack - coll, 0.0)

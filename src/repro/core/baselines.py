@@ -180,7 +180,8 @@ def object_access(cfg: PlaneConfig, s: st.PlaneState, obj_ids: jnp.ndarray,
 
 
 # memoized jit entry points (one compilation per config per process — see
-# plane.jitted_access; wrappers normalize ``mode`` before the cache lookup)
+# plane.jitted_access; wrappers normalize ``mode`` before the cache lookup;
+# ``donate=True`` as in plane.jitted_execute_access)
 
 @functools.lru_cache(maxsize=None)
 def _jitted_paging_access(cfg: PlaneConfig, mode: str):
@@ -214,13 +215,14 @@ def jitted_plan_paging(cfg: PlaneConfig, degraded: bool = False):
 
 
 @functools.lru_cache(maxsize=None)
-def _jitted_execute_paging(cfg: PlaneConfig, mode: str):
-    return jax.jit(st.named_partial(batch_lib.execute_paging_access, cfg,
-                                    mode=mode))
+def _jitted_execute_paging(cfg: PlaneConfig, mode: str, donate: bool = False):
+    return st.jit_state(st.named_partial(batch_lib.execute_paging_access,
+                                         cfg, mode=mode), donate)
 
 
-def jitted_execute_paging(cfg: PlaneConfig, mode: str | None = None):
-    return _jitted_execute_paging(cfg, mode or cfg.access_mode)
+def jitted_execute_paging(cfg: PlaneConfig, mode: str | None = None, *,
+                          donate: bool = False):
+    return _jitted_execute_paging(cfg, mode or cfg.access_mode, donate)
 
 
 @functools.lru_cache(maxsize=None)
@@ -234,10 +236,12 @@ def jitted_plan_object(cfg: PlaneConfig, degraded: bool = False):
 
 
 @functools.lru_cache(maxsize=None)
-def _jitted_execute_object(cfg: PlaneConfig, mode: str):
-    return jax.jit(st.named_partial(batch_lib.execute_object_access, cfg,
-                                    mode=mode, reclaim=object_reclaim))
+def _jitted_execute_object(cfg: PlaneConfig, mode: str, donate: bool = False):
+    return st.jit_state(st.named_partial(batch_lib.execute_object_access,
+                                         cfg, mode=mode,
+                                         reclaim=object_reclaim), donate)
 
 
-def jitted_execute_object(cfg: PlaneConfig, mode: str | None = None):
-    return _jitted_execute_object(cfg, mode or cfg.access_mode)
+def jitted_execute_object(cfg: PlaneConfig, mode: str | None = None, *,
+                          donate: bool = False):
+    return _jitted_execute_object(cfg, mode or cfg.access_mode, donate)

@@ -75,6 +75,9 @@ def update(cfg: PlaneConfig, s: st.PlaneState, obj_ids: jnp.ndarray,
 # (lru_cache keys raw call args, so ``f(cfg)`` and ``f(cfg, "batch")``
 # would otherwise compile twice).  Each program is compiled under its entry
 # point's name (``st.named_partial``): ``jit_plan_access`` and so on.
+# ``donate=True`` gives a state-returning program's donating form
+# (``st.DonatingProgram``), for a caller that holds one live state: the
+# serving engine.  The default keeps the input state readable.
 
 @functools.lru_cache(maxsize=None)
 def _jitted_access(cfg: PlaneConfig, mode: str):
@@ -109,26 +112,30 @@ def jitted_plan_access(cfg: PlaneConfig, degraded: bool = False):
 
 
 @functools.lru_cache(maxsize=None)
-def _jitted_execute_access(cfg: PlaneConfig, mode: str):
-    return jax.jit(st.named_partial(batch_lib.execute_access, cfg,
-                                    mode=mode))
+def _jitted_execute_access(cfg: PlaneConfig, mode: str, donate: bool = False):
+    return st.jit_state(st.named_partial(batch_lib.execute_access, cfg,
+                                         mode=mode), donate)
 
 
-def jitted_execute_access(cfg: PlaneConfig, mode: str | None = None):
-    return _jitted_execute_access(cfg, mode or cfg.access_mode)
+def jitted_execute_access(cfg: PlaneConfig, mode: str | None = None, *,
+                          donate: bool = False):
+    return _jitted_execute_access(cfg, mode or cfg.access_mode, donate)
 
 
 @functools.lru_cache(maxsize=None)
 def _jitted_evacuate(cfg: PlaneConfig, garbage_threshold: float | None,
-                     max_pages: int, clear_access: bool):
-    return jax.jit(st.named_partial(
+                     max_pages: int, clear_access: bool,
+                     donate: bool = False):
+    return st.jit_state(st.named_partial(
         evacuate, cfg, garbage_threshold=garbage_threshold,
-        max_pages=max_pages, clear_access=clear_access))
+        max_pages=max_pages, clear_access=clear_access), donate)
 
 
 def jitted_evacuate(cfg: PlaneConfig, garbage_threshold: float | None = None,
-                    max_pages: int = 16, clear_access: bool = True):
-    return _jitted_evacuate(cfg, garbage_threshold, max_pages, clear_access)
+                    max_pages: int = 16, clear_access: bool = True, *,
+                    donate: bool = False):
+    return _jitted_evacuate(cfg, garbage_threshold, max_pages, clear_access,
+                            donate)
 
 
 @functools.lru_cache(maxsize=None)
@@ -161,12 +168,12 @@ def jitted_execute_evacuate(cfg: PlaneConfig,
 
 
 @functools.lru_cache(maxsize=None)
-def _jitted_advance_epoch(cfg: PlaneConfig):
-    return jax.jit(st.named_partial(advance_epoch, cfg))
+def _jitted_advance_epoch(cfg: PlaneConfig, donate: bool = False):
+    return st.jit_state(st.named_partial(advance_epoch, cfg), donate)
 
 
-def jitted_advance_epoch(cfg: PlaneConfig):
-    return _jitted_advance_epoch(cfg)
+def jitted_advance_epoch(cfg: PlaneConfig, *, donate: bool = False):
+    return _jitted_advance_epoch(cfg, donate)
 
 
 # --------------------------------------------------------------------------

@@ -631,15 +631,17 @@ def jitted_phase_probe(cfg: ShardedPlaneConfig, phase: str, mesh):
 # memoized jit entry points (mesh=None -> the single-device oracle)
 # --------------------------------------------------------------------------
 
-def _jit_on_mesh(name: str, body, mesh, in_specs, out_specs):
+def _jit_on_mesh(name: str, body, mesh, in_specs, out_specs,
+                 donate: bool = False):
     """``body`` run per shard on the ``far`` mesh as one program, compiled
-    as ``jit_<name>``.  check_vma=False: the plane engine contains
-    fori/while loops, which shard_map's varying-axes checker cannot rule
-    on (the state is genuinely sharded anyway)."""
+    as ``jit_<name>`` (state-donating with ``donate``, ``st.jit_state``).
+    check_vma=False: the plane engine contains fori/while loops, which
+    shard_map's varying-axes checker cannot rule on (the state is
+    genuinely sharded anyway)."""
     fn = jax.shard_map(body, mesh=mesh, in_specs=in_specs,
                        out_specs=out_specs, check_vma=False)
     fn.__name__ = name
-    return jax.jit(fn)
+    return st.jit_state(fn, donate)
 
 
 def _state_specs(cfg: ShardedPlaneConfig):
@@ -651,47 +653,51 @@ def _state_specs(cfg: ShardedPlaneConfig):
 
 @functools.lru_cache(maxsize=None)
 def _jitted_access(cfg: ShardedPlaneConfig, mode, mesh, with_served,
-                   degraded):
+                   degraded, donate=False):
     if mesh is None:
-        return jax.jit(st.named_partial(access, cfg, mode=mode,
-                                        degraded=degraded,
-                                        with_served=with_served))
+        return st.jit_state(st.named_partial(access, cfg, mode=mode,
+                                             degraded=degraded,
+                                             with_served=with_served),
+                            donate)
     sp = _state_specs(cfg)
     outs = ((sp, P("far"), P("far")) if with_served else (sp, P("far")))
     return _jit_on_mesh(
         "sharded_access",
         partial(_access_body, cfg, mode, degraded, with_served),
-        mesh, (sp, P("far")), outs)
+        mesh, (sp, P("far")), outs, donate)
 
 
 def jitted_access(cfg: ShardedPlaneConfig, mode=None, mesh=None, *,
-                  with_served: bool = False, degraded: bool = False):
+                  with_served: bool = False, degraded: bool = False,
+                  donate: bool = False):
     """``(states, ids [S, R]) -> (states, rows [S, R, D])``; ``mesh=None``
     runs the vmap oracle on one device, a ``far`` mesh runs shard_map.
     ``with_served=True`` appends the fault model's per-request ``served
     [S, R]`` verdicts; ``degraded=True`` compiles the hits-only
-    circuit-breaker variant."""
+    circuit-breaker variant; ``donate=True`` the state-donating form
+    (``st.DonatingProgram``)."""
     return _jitted_access(cfg, mode or cfg.shard.access_mode, mesh,
-                          with_served, degraded)
+                          with_served, degraded, donate)
 
 
 @functools.lru_cache(maxsize=None)
-def _jitted_access_degmask(cfg: ShardedPlaneConfig, mode, mesh, with_served):
+def _jitted_access_degmask(cfg: ShardedPlaneConfig, mode, mesh, with_served,
+                           donate=False):
     if mesh is None:
         def access_degmask(states, ids, deg):
             return access(cfg, states, ids, mode=mode, degraded=deg,
                           with_served=with_served)
-        return jax.jit(access_degmask)
+        return st.jit_state(access_degmask, donate)
     sp = _state_specs(cfg)
     outs = ((sp, P("far"), P("far")) if with_served else (sp, P("far")))
     return _jit_on_mesh(
         "sharded_access_degmask",
         partial(_access_body_degmask, cfg, mode, with_served),
-        mesh, (sp, P("far"), P("far")), outs)
+        mesh, (sp, P("far"), P("far")), outs, donate)
 
 
 def jitted_access_degmask(cfg: ShardedPlaneConfig, mode=None, mesh=None, *,
-                          with_served: bool = True):
+                          with_served: bool = True, donate: bool = False):
     """``(states, ids [S, R], deg [S] bool) -> (states, rows, served?)``:
     the per-shard circuit-breaker entry point (DESIGN.md §6c).  Shards
     with ``deg[k]`` set serve local hits only (no remote I/O planned);
@@ -699,7 +705,7 @@ def jitted_access_degmask(cfg: ShardedPlaneConfig, mode=None, mesh=None, *,
     ``jitted_access`` program — passing an all-False mask reproduces it
     exactly, so the engine compiles ONE program for every breaker state."""
     return _jitted_access_degmask(cfg, mode or cfg.shard.access_mode, mesh,
-                                  with_served)
+                                  with_served, donate)
 
 
 @functools.lru_cache(maxsize=None)
@@ -716,38 +722,38 @@ def jitted_update(cfg: ShardedPlaneConfig, mode=None, mesh=None):
 
 
 @functools.lru_cache(maxsize=None)
-def _jitted_advance_epoch(cfg: ShardedPlaneConfig, mesh):
+def _jitted_advance_epoch(cfg: ShardedPlaneConfig, mesh, donate=False):
     if mesh is None:
-        return jax.jit(st.named_partial(advance_epoch, cfg))
+        return st.jit_state(st.named_partial(advance_epoch, cfg), donate)
     sp = _state_specs(cfg)
     return _jit_on_mesh("sharded_advance_epoch", partial(_epoch_body, cfg),
-                        mesh, (sp,), sp)
+                        mesh, (sp,), sp, donate)
 
 
-def jitted_advance_epoch(cfg: ShardedPlaneConfig, mesh=None):
-    return _jitted_advance_epoch(cfg, mesh)
+def jitted_advance_epoch(cfg: ShardedPlaneConfig, mesh=None, *,
+                         donate: bool = False):
+    return _jitted_advance_epoch(cfg, mesh, donate)
 
 
 @functools.lru_cache(maxsize=None)
 def _jitted_evacuate(cfg: ShardedPlaneConfig, garbage_threshold, max_pages,
-                     clear_access, mesh):
+                     clear_access, mesh, donate=False):
     if mesh is None:
-        return jax.jit(st.named_partial(evacuate, cfg,
-                                        garbage_threshold=garbage_threshold,
-                                        max_pages=max_pages,
-                                        clear_access=clear_access))
+        return st.jit_state(st.named_partial(
+            evacuate, cfg, garbage_threshold=garbage_threshold,
+            max_pages=max_pages, clear_access=clear_access), donate)
     sp = _state_specs(cfg)
     return _jit_on_mesh("sharded_evacuate",
                         partial(_evac_body, cfg, garbage_threshold,
                                 max_pages, clear_access),
-                        mesh, (sp,), sp)
+                        mesh, (sp,), sp, donate)
 
 
 def jitted_evacuate(cfg: ShardedPlaneConfig, garbage_threshold=None,
                     max_pages: int = 16, clear_access: bool = True,
-                    mesh=None):
+                    mesh=None, *, donate: bool = False):
     return _jitted_evacuate(cfg, garbage_threshold, max_pages, clear_access,
-                            mesh)
+                            mesh, donate)
 
 
 # --------------------------------------------------------------------------

@@ -166,6 +166,45 @@ def named_partial(fn, *args, **kwargs):
     return p
 
 
+class DonatingProgram:
+    """``jax.jit(fn)`` for ``fn(state, *args)`` that returns the new state
+    (alone or first in a tuple), with the state's buffers donated to the
+    result: XLA writes the new state over the old one, so the program
+    copies no slab or frame pool and the runtime allocates no fresh state
+    per call.  The old state is deleted.  ``state.stats`` is passed apart
+    and not donated, so counters a caller snapshotted stay readable after
+    later calls.  Called and lowered like ``jax.jit(fn)``, and compiled
+    under ``fn``'s name.  A state already donated raises before dispatch:
+    on a mesh, the runtime would otherwise launch the program on the
+    devices whose shards it can read, and leave them waiting in the
+    exchange for the one it cannot."""
+
+    def __init__(self, fn):
+        def program(rest, stats, *args):
+            return fn(rest._replace(stats=stats), *args)
+        program.__name__ = fn.__name__
+        self._jit = jax.jit(program, donate_argnums=0)
+
+    def __call__(self, s, *args):
+        if s.slab.is_deleted():
+            raise ValueError(f"{self._jit.__name__}: the state was donated "
+                             "to an earlier call and is deleted")
+        return self._jit(s._replace(stats=None), s.stats, *args)
+
+    def lower(self, s, *args):
+        return self._jit.lower(s._replace(stats=None), s.stats, *args)
+
+    def _cache_size(self) -> int:
+        return self._jit._cache_size()
+
+
+def jit_state(fn, donate: bool):
+    """``jax.jit(fn)`` for a state-returning ``fn``; with ``donate``, its
+    state-donating form (``DonatingProgram``).  Library entry points stay
+    functional; the serving engine, which holds one live state, donates."""
+    return DonatingProgram(fn) if donate else jax.jit(fn)
+
+
 @functools.lru_cache(maxsize=None)
 def jitted_create(cfg: PlaneConfig):
     """``create`` as one compiled program: the slab is built in place, so

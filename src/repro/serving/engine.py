@@ -281,7 +281,12 @@ class Engine:
                         or cfg.max_retries > 0 or cfg.breaker_threshold > 0)
         breaker_on = self._robust and cfg.breaker_threshold > 0
         # memoized jit entry points: engines sharing a PlaneConfig share one
-        # compiled executable per op (continuous batching spins up several)
+        # compiled executable per op (continuous batching spins up several).
+        # The engine holds exactly one live state, so every program that
+        # returns it donates it (``donate=True``): XLA writes the new state
+        # over the old, with no copy of the slab and no fresh allocation per
+        # call.  ``state.stats`` is not donated, so a snapshot of the
+        # counters stays readable after later ticks.
         self._plan = self._exec = self._access = None
         self._evac = self._epoch = self._traffic = None
         self._evac_slice = self._evac_slice_clear = None
@@ -301,34 +306,37 @@ class Engine:
             # Robust engines take the served-channel variant (the verdicts
             # ride the exchange back with the rows).
             self._access = shardplane.jitted_access(
-                scfg, cfg.mode, mesh, with_served=self._robust)
+                scfg, cfg.mode, mesh, with_served=self._robust, donate=True)
             if breaker_on:
                 # ONE compiled program for every breaker state: the [S]
                 # degraded mask arrives as data, so any mix of tripped and
                 # healthy shards dispatches without recompiling (all-False
                 # reproduces the plain program bit-identically)
                 self._access_degmask = shardplane.jitted_access_degmask(
-                    scfg, cfg.mode, mesh, with_served=True)
+                    scfg, cfg.mode, mesh, with_served=True, donate=True)
             if cfg.plane == "hybrid":
-                self._evac = shardplane.jitted_evacuate(scfg, mesh=mesh)
+                self._evac = shardplane.jitted_evacuate(scfg, mesh=mesh,
+                                                        donate=True)
                 if cfg.evac_budget > 0:
                     self._evac_slice = shardplane.jitted_evacuate(
                         scfg, max_pages=cfg.evac_budget, clear_access=False,
-                        mesh=mesh)
+                        mesh=mesh, donate=True)
                     self._evac_slice_clear = shardplane.jitted_evacuate(
                         scfg, max_pages=cfg.evac_budget, clear_access=True,
-                        mesh=mesh)
+                        mesh=mesh, donate=True)
                 if epoch_on:
-                    self._epoch = shardplane.jitted_advance_epoch(scfg, mesh)
+                    self._epoch = shardplane.jitted_advance_epoch(
+                        scfg, mesh, donate=True)
             tcfg = scfg.shard
         elif cfg.plane == "hybrid":
             self.state = state_lib.jitted_create(pcfg)(initial)
             self._plan = plane_lib.jitted_plan_access(pcfg)
-            self._exec = plane_lib.jitted_execute_access(pcfg, cfg.mode)
+            self._exec = plane_lib.jitted_execute_access(pcfg, cfg.mode,
+                                                         donate=True)
             if breaker_on:
                 self._plan_deg = plane_lib.jitted_plan_access(
                     pcfg, degraded=True)
-            self._evac = plane_lib.jitted_evacuate(pcfg)
+            self._evac = plane_lib.jitted_evacuate(pcfg, donate=True)
             if cfg.evac_budget > 0:
                 # background slices: each is plan_evacuate+execute_evacuate
                 # composed into ONE async device call (a two-call split
@@ -336,16 +344,20 @@ class Engine:
                 # land in the same gap anyway); same 16-page budget per
                 # evac_every round as the foreground call
                 self._evac_slice = plane_lib.jitted_evacuate(
-                    pcfg, max_pages=cfg.evac_budget, clear_access=False)
+                    pcfg, max_pages=cfg.evac_budget, clear_access=False,
+                    donate=True)
                 self._evac_slice_clear = plane_lib.jitted_evacuate(
-                    pcfg, max_pages=cfg.evac_budget, clear_access=True)
+                    pcfg, max_pages=cfg.evac_budget, clear_access=True,
+                    donate=True)
             if epoch_on:
-                self._epoch = plane_lib.jitted_advance_epoch(pcfg)
+                self._epoch = plane_lib.jitted_advance_epoch(pcfg,
+                                                             donate=True)
             tcfg = pcfg
         elif cfg.plane == "paging":
             self.state = state_lib.jitted_create(pcfg)(initial)
             self._plan = baselines.jitted_plan_paging(pcfg)
-            self._exec = baselines.jitted_execute_paging(pcfg, cfg.mode)
+            self._exec = baselines.jitted_execute_paging(pcfg, cfg.mode,
+                                                         donate=True)
             if breaker_on:
                 self._plan_deg = baselines.jitted_plan_paging(
                     pcfg, degraded=True)
@@ -353,7 +365,8 @@ class Engine:
         elif cfg.plane == "object":
             self.state = state_lib.jitted_create(pcfg)(initial)
             self._plan = baselines.jitted_plan_object(pcfg)
-            self._exec = baselines.jitted_execute_object(pcfg, cfg.mode)
+            self._exec = baselines.jitted_execute_object(pcfg, cfg.mode,
+                                                         donate=True)
             if breaker_on:
                 self._plan_deg = baselines.jitted_plan_object(
                     pcfg, degraded=True)
@@ -423,23 +436,28 @@ class Engine:
                                        self._plan(self.state, warm))
         if self._evac is not None:
             self.state = self._evac(self.state)
+        # the programs below are warmed with their results discarded, so
+        # the warm state stays identical to a plain engine's (the
+        # fault-free equivalence tests depend on it); a donating one runs
+        # on a copy of the state, which it deletes
+
+        def spare():
+            return jax.tree.map(jnp.copy, self.state)
         if self._evac_slice is not None:
-            # compile-cache the background-slice pair (results discarded)
-            jax.block_until_ready(self._evac_slice(self.state))
-            jax.block_until_ready(self._evac_slice_clear(self.state))
+            # compile-cache the background-slice pair
+            jax.block_until_ready(self._evac_slice(spare()))
+            jax.block_until_ready(self._evac_slice_clear(spare()))
         if self._epoch is not None:
-            jax.block_until_ready(self._epoch(self.state))
+            jax.block_until_ready(self._epoch(spare()))
         if self._traffic is not None:
             jax.block_until_ready(self._traffic(self.state))
         # warm the degraded/probe entries too — compiling them lazily would
-        # land the jit cost inside the fault window and pollute its p99.
-        # Results are discarded: warmup state stays identical to a plain
-        # engine's (the fault-free equivalence tests depend on it).
+        # land the jit cost inside the fault window and pollute its p99
         if self._plan_deg is not None:
             jax.block_until_ready(self._plan_deg(self.state, warm))
         if self._access_degmask is not None:
             jax.block_until_ready(self._access_degmask(
-                self.state, warm, jnp.zeros((cfg.shards,), bool)))
+                spare(), warm, jnp.zeros((cfg.shards,), bool)))
         if self._health is not None:
             jax.block_until_ready(self._health(self.state))
         self.state = self.state._replace(
