@@ -762,7 +762,7 @@ def jitted_evacuate(cfg: ShardedPlaneConfig, garbage_threshold=None,
 
 def stats_total(states) -> st.PlaneStats:
     """Global counters: sum each stat over the shard axis."""
-    return st.PlaneStats(*[jnp.sum(x, axis=0) for x in states.stats])
+    return st.PlaneStats(jnp.sum(states.stats.counts, axis=0))
 
 
 def paging_fraction(cfg: ShardedPlaneConfig, states) -> jnp.ndarray:

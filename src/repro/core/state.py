@@ -12,40 +12,73 @@ from . import layout
 from .layout import FREE, LOCAL, REMOTE, PlaneConfig
 
 
-class PlaneStats(NamedTuple):
+@jax.tree_util.register_pytree_node_class
+class PlaneStats:
     """Event counters (int32 counts; byte totals derived host-side via
     ``PlaneConfig.row_bytes``/``page_bytes`` so no 64-bit arithmetic is needed
-    on device)."""
+    on device).
 
-    hits: jnp.ndarray            # resident accesses
-    misses: jnp.ndarray          # faulting accesses
-    page_ins: jnp.ndarray        # paging-path ingress events (pages)
-    obj_ins: jnp.ndarray         # runtime-path ingress events (objects)
-    page_outs: jnp.ndarray       # egress events (pages)
-    dirty_page_outs: jnp.ndarray # egress events that wrote data back
-    psf_to_paging: jnp.ndarray   # PSF flips runtime->paging (page-out / epoch)
-    psf_to_runtime: jnp.ndarray  # PSF flips paging->runtime (page-out / epoch)
-    evac_moved: jnp.ndarray      # objects moved by the evacuator
-    evac_pages: jnp.ndarray      # pages reclaimed by the evacuator
-    obj_outs: jnp.ndarray        # object-granular egress (object-plane baseline)
-    lru_scans: jnp.ndarray       # objects scanned by object-level LRU (baseline)
-    prefetch_issued: jnp.ndarray # prefetch page-ins (subset of page_ins)
-    prefetch_used: jnp.ndarray   # prefetched pages later hit by a demand access
-    epochs: jnp.ndarray          # advance_epoch invocations (governor runs)
-    ingress_spills: jnp.ndarray  # sharded-exchange requests deferred a round
-    #                              (per_shard_budget overflow, shardplane)
-    fetch_failures: jnp.ndarray  # planned fetches masked off by the fault
-    #                              model (repro.core.faults) — each left its
-    #                              request unserved this tick
-    egress_failures: jnp.ndarray # remote writes (eviction writeback, remote
-    #                              update, evacuation victim, KV append)
-    #                              blocked by the fault model — the write was
-    #                              skipped atomically, neither tier mutated
+    The counters travel as ONE int32 array, ``counts`` (``[18]``, or ``[S,
+    18]`` stacked over shards, in ``_fields`` order): the runtime allocates
+    each output buffer of a program at a fixed cost, so eighteen scalar
+    counters cost each dispatch seventeen buffers more than one array.  The
+    named fields are read-only views (``stats.hits`` is ``counts[..., 0]``),
+    and ``_asdict()`` gives ``{field: view}``; ``bump`` writes.  One pytree
+    leaf, so ``jax.device_get``, ``jax.tree.map`` and a ``P("far")`` spec
+    see one array."""
+
+    _fields = (
+        "hits",             # resident accesses
+        "misses",           # faulting accesses
+        "page_ins",         # paging-path ingress events (pages)
+        "obj_ins",          # runtime-path ingress events (objects)
+        "page_outs",        # egress events (pages)
+        "dirty_page_outs",  # egress events that wrote data back
+        "psf_to_paging",    # PSF flips runtime->paging (page-out / epoch)
+        "psf_to_runtime",   # PSF flips paging->runtime (page-out / epoch)
+        "evac_moved",       # objects moved by the evacuator
+        "evac_pages",       # pages reclaimed by the evacuator
+        "obj_outs",         # object-granular egress (object-plane baseline)
+        "lru_scans",        # objects scanned by object-level LRU (baseline)
+        "prefetch_issued",  # prefetch page-ins (subset of page_ins)
+        "prefetch_used",    # prefetched pages later hit by a demand access
+        "epochs",           # advance_epoch invocations (governor runs)
+        "ingress_spills",   # sharded-exchange requests deferred a round
+        #                     (per_shard_budget overflow, shardplane)
+        "fetch_failures",   # planned fetches masked off by the fault model
+        #                     (repro.core.faults) — each left its request
+        #                     unserved this tick
+        "egress_failures",  # remote writes (eviction writeback, remote
+        #                     update, evacuation victim, KV append) blocked
+        #                     by the fault model — the write was skipped
+        #                     atomically, neither tier mutated
+    )
+    __slots__ = ("counts",)
+
+    def __init__(self, counts):
+        self.counts = counts
+
+    def tree_flatten(self):
+        return (self.counts,), None
+
+    @classmethod
+    def tree_unflatten(cls, _, children):
+        return cls(*children)
 
     @classmethod
     def zeros(cls) -> "PlaneStats":
-        z = jnp.zeros((), jnp.int32)
-        return cls(*([z] * len(cls._fields)))
+        return cls(jnp.zeros((len(cls._fields),), jnp.int32))
+
+    def _asdict(self) -> dict:
+        return {f: getattr(self, f) for f in self._fields}
+
+    def __repr__(self) -> str:
+        return f"PlaneStats({self.counts!r})"
+
+
+for _i, _f in enumerate(PlaneStats._fields):
+    setattr(PlaneStats, _f, property(lambda self, i=_i: self.counts[..., i]))
+del _i, _f
 
 
 class PlaneState(NamedTuple):
@@ -213,8 +246,16 @@ def jitted_create(cfg: PlaneConfig):
 
 
 def bump(stats: PlaneStats, **deltas) -> PlaneStats:
-    """Increment named counters."""
-    return stats._replace(**{k: getattr(stats, k) + v for k, v in deltas.items()})
+    """Increment named counters: one add of a delta vector whose entries
+    sit at the fields' static indices (a delta of shape ``[S]`` bumps each
+    shard of a stacked ``[S, 18]`` array)."""
+    unknown = set(deltas) - set(PlaneStats._fields)
+    if unknown:
+        raise AttributeError(f"PlaneStats has no counter {sorted(unknown)}")
+    delta = [jnp.asarray(deltas.get(f, 0), jnp.int32)
+             for f in PlaneStats._fields]
+    return PlaneStats(stats.counts
+                      + jnp.stack(jnp.broadcast_arrays(*delta), axis=-1))
 
 
 # --------------------------------------------------------------------------

@@ -112,20 +112,22 @@ def _served(stats) -> int:
 
 
 def _state_programs(eng):
-    """``(program, args)`` of each state-returning program ``eng``
-    dispatches."""
+    """``(program, args, fresh)`` of each state-returning program ``eng``
+    dispatches, ``fresh`` the outputs it cannot write over its donated
+    state: its results besides the state (rows; the served flags of the
+    breaker's program) and the one counter array."""
     s = eng.state
     ids = jnp.zeros((BATCH,), jnp.int32)
     if eng._access is not None:
         ids = ids.reshape(s.step.shape[0], -1)
-        out = [(eng._access, (s, ids))]
+        out = [(eng._access, (s, ids), 2)]
         if getattr(eng, "_access_degmask", None) is not None:
             deg = jnp.zeros(s.step.shape, bool)
-            out.append((eng._access_degmask, (s, ids, deg)))
+            out.append((eng._access_degmask, (s, ids, deg), 3))
     else:
-        out = [(eng._exec, (s, ids, jax.eval_shape(eng._plan, s, ids)))]
+        out = [(eng._exec, (s, ids, jax.eval_shape(eng._plan, s, ids)), 2)]
     if eng._evac is not None:
-        out += [(eng._evac, (s,)), (eng._epoch, (s,))]
+        out += [(eng._evac, (s,), 1), (eng._epoch, (s,), 1)]
     return out
 
 
@@ -138,7 +140,7 @@ def test_submit_deletes_the_previous_state(kind):
         old = eng.state
         eng.submit(_ids(rng))
         assert old.slab.is_deleted() and old.frames.is_deleted(), tick
-        assert not any(x.is_deleted() for x in old.stats), tick
+        assert not old.stats.counts.is_deleted(), tick
     eng.drain()
 
 
@@ -159,15 +161,20 @@ def test_stats_snapshot_reads_after_later_ticks(kind):
 @pytest.mark.parametrize("kind", KINDS)
 def test_state_programs_alias_the_whole_state(kind):
     """Every donated buffer is aliased to an output (none is left unusable,
-    which JAX would warn of), the slab and the frame pool among them."""
+    which JAX would warn of), the slab and the frame pool among them, and
+    the runtime allocates only the outputs left: the results besides the
+    state and the counters, one array of them."""
     eng = _build(kind)
     s = eng.state
     donated = jax.tree.leaves(s._replace(stats=None))
     donated_bytes = sum(x.nbytes for x in donated)
     assert donated_bytes > s.slab.nbytes + s.frames.nbytes
-    for program, args in _state_programs(eng):
+    for program, args, fresh in _state_programs(eng):
         lowered = program.lower(*args)
-        assert lowered.as_text().count("tf.aliasing_output") == len(donated)
+        aliased = lowered.as_text().count("tf.aliasing_output")
+        assert aliased == len(donated)
+        outputs = len(jax.tree.leaves(lowered.out_info))
+        assert outputs - aliased == fresh, program
         memory = lowered.compile().memory_analysis()
         assert memory.alias_size_in_bytes == donated_bytes
 
@@ -180,7 +187,7 @@ def test_a_donated_state_raises_before_dispatch(kind):
     rng = np.random.RandomState(4)
     old = eng.state
     eng.submit(_ids(rng))
-    for program, args in _state_programs(eng):
+    for program, args, _ in _state_programs(eng):
         with pytest.raises(ValueError, match="donated"):
             program(old, *args[1:])
     ids = _ids(rng)
